@@ -22,33 +22,23 @@ from typing import Any, Callable, Optional
 
 import jax
 import jax.numpy as jnp
-from jax.sharding import NamedSharding
 
 from repro.core.placement import PlacementPlan
 
 
 def _supported_kind(kind: str) -> Optional[str]:
-    """Single-memory backends collapse all tiers — same policy (and same
-    cached probe) as the harness's tier placement."""
+    """Same memory-kind policy (and cached probe) as the harness's tier
+    placement: only a CPU backend collapses a kind it lacks."""
     from repro.heimdall.harness import supported_memory_kind
     return supported_memory_kind(kind)
 
 
-def with_memory_kind(sharding: NamedSharding, kind: str) -> NamedSharding:
-    return NamedSharding(sharding.mesh, sharding.spec,
-                         memory_kind=_supported_kind(kind))
-
-
 def put_tree(tree, kind: str):
-    """device_put a pytree into a memory kind (keeping shardings)."""
-    def put(x):
-        s = x.sharding if hasattr(x, "sharding") else None
-        if isinstance(s, NamedSharding):
-            return jax.device_put(x, with_memory_kind(s, kind))
-        return jax.device_put(
-            x, jax.sharding.SingleDeviceSharding(
-                jax.devices()[0], memory_kind=_supported_kind(kind)))
-    return jax.tree.map(put, tree)
+    """device_put a pytree of arrays into a memory kind, each on the
+    devices its own sharding names."""
+    mk = _supported_kind(kind)
+    return jax.tree.map(
+        lambda x: jax.device_put(x, x.sharding.with_memory_kind(mk)), tree)
 
 
 def state_shardings(model, plan: PlacementPlan):

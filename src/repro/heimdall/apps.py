@@ -26,8 +26,7 @@ def _tiny_model(arch: str = "yi-9b"):
                                    d_ff=256)
     mesh = make_host_mesh()
     model = Model.create(cfg, mesh, ParallelConfig(remat="none"))
-    params = model.init(jax.random.key(0))
-    params = jax.tree.map(lambda p: p.astype(jnp.bfloat16), params)
+    params = model.init(jax.random.key(0), dtype=jnp.bfloat16)
     return cfg, model, params
 
 

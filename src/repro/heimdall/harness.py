@@ -111,32 +111,33 @@ def time_fn(fn: Callable, *args, warmup: int = 3, iters: int = 10,
 
 @functools.cache
 def backend_memory_kinds():
-    """Memory kinds the default device addresses, or None if the backend
-    has no memories API. Cached — called per array placement."""
-    try:
-        return frozenset(m.kind
-                         for m in jax.devices()[0].addressable_memories())
-    except Exception:       # noqa: BLE001 — backend without memories API
-        return None
+    """Memory kinds the default device addresses. Cached — called per
+    array placement."""
+    return frozenset(m.kind for m in jax.devices()[0].addressable_memories())
 
 
 def supported_memory_kind(kind):
-    """The requested memory kind, or None (= default memory) when the
-    backend cannot address it — the single collapse policy shared by
-    tier_sharding and core.offload."""
+    """The requested memory kind where the device addresses it — the single
+    policy shared by tier_sharding and core.offload. Only the CPU backend
+    collapses a missing kind into default memory (None); on an accelerator
+    an unaddressable kind raises rather than silently moving a tier."""
     kinds = backend_memory_kinds()
-    if kinds is None or kind in kinds:
+    if kind in kinds:
         return kind
-    return None
+    platform = jax.devices()[0].platform
+    if platform == "cpu":
+        return None
+    raise ValueError(f"memory kind {kind!r} is not addressable on "
+                     f"{platform}: it has {sorted(kinds)}")
 
 
 def tier_sharding(memory_kind: str = "device",
                   mesh=None) -> NamedSharding:
     """Sharding pinned to a memory tier.
 
-    On single-memory backends (e.g. this CPU container, which only exposes
-    ``unpinned_host``) all tiers collapse into the default memory — relative
-    tier numbers compress, as micro.py's header notes — instead of erroring.
+    On a CPU backend without the kind, all tiers collapse into the default
+    memory (relative tier numbers compress, as micro.py's header notes);
+    elsewhere a missing kind raises.
     """
     if mesh is None:
         from repro.launch.mesh import make_mesh

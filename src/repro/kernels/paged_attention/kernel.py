@@ -27,6 +27,11 @@ from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
 LANES = 128
+# Scale planes of the int8 kernel travel in (8, 128) f32 blocks, the
+# smallest tile the TPU compiler accepts: each block holds the scales of
+# SCALE_BLOCK consecutive (page, kv_head) rows, row-major.
+SCALE_ROWS = 8
+SCALE_BLOCK = SCALE_ROWS * LANES
 
 
 def _flash_page_step(seq_lens, q, k, v, o_ref, m_ref, l_ref, acc_ref, *,
@@ -88,6 +93,16 @@ def _kernel(block_table, seq_lens,            # scalar-prefetch (SMEM)
                      scale=scale, G=G, hkv=hkv)
 
 
+def _pick_scale(s_ref, r):
+    """The scale at flat position ``r`` of an (8, 128) scale block, as a
+    (1, 1) array (masked reduce: no dynamic sublane/lane indexing)."""
+    rows = jax.lax.broadcasted_iota(jnp.int32, s_ref.shape, 0)
+    lanes = jax.lax.broadcasted_iota(jnp.int32, s_ref.shape, 1)
+    sel = jnp.where(rows * LANES + lanes == r, s_ref[...], 0.0)
+    return jnp.sum(jnp.sum(sel, axis=1, keepdims=True), axis=0,
+                   keepdims=True)
+
+
 def _kernel_quant(block_table, seq_lens,      # scalar-prefetch (SMEM)
                   q_ref, k_ref, v_ref, ks_ref, vs_ref, o_ref,
                   m_ref, l_ref, acc_ref, *,
@@ -95,9 +110,12 @@ def _kernel_quant(block_table, seq_lens,      # scalar-prefetch (SMEM)
                   hkv: int):
     """int8 page blocks + per-(page, head) scale blocks: dequantize in
     registers right after the VMEM DMA — the DMA itself moved int8."""
+    bh = pl.program_id(0)
+    row = block_table[bh // hkv, pl.program_id(1)] * hkv + bh % hkv
+    r = row % SCALE_BLOCK
     q = q_ref[0].astype(jnp.float32)                 # (G, d)
-    k = k_ref[0].astype(jnp.float32) * ks_ref[0, 0]  # (page, d) from int8
-    v = v_ref[0].astype(jnp.float32) * vs_ref[0, 0]
+    k = k_ref[0].astype(jnp.float32) * _pick_scale(ks_ref, r)
+    v = v_ref[0].astype(jnp.float32) * _pick_scale(vs_ref, r)
     _flash_page_step(seq_lens, q, k, v, o_ref, m_ref, l_ref, acc_ref,
                      page=page, n_pages_per_seq=n_pages_per_seq,
                      scale=scale, G=G, hkv=hkv)
@@ -177,12 +195,16 @@ def paged_attention_quant(q: jax.Array, k_pages: jax.Array,
     qf = q.reshape(B, Hkv, G, d).reshape(B * Hkv, G, d)
     kf = k_pages.transpose(0, 2, 1, 3).reshape(n_pages * Hkv, page, d)
     vf = v_pages.transpose(0, 2, 1, 3).reshape(n_pages * Hkv, page, d)
-    # scale planes ride as (n_pages*Hkv, LANES) so each page block's scalar
-    # lands in VMEM next to its int8 page (lane-width row per block)
-    ksf = jnp.broadcast_to(k_scales.reshape(n_pages * Hkv, 1),
-                           (n_pages * Hkv, LANES))
-    vsf = jnp.broadcast_to(v_scales.reshape(n_pages * Hkv, 1),
-                           (n_pages * Hkv, LANES))
+    # scale planes: the n_pages*Hkv scalars row-major in (8, 128) blocks;
+    # each grid step DMAs the block holding its page's scale
+    n_rows = n_pages * Hkv
+    n_blk = -(-n_rows // SCALE_BLOCK)
+
+    def plane(s):
+        flat = jnp.pad(s.reshape(n_rows).astype(jnp.float32),
+                       (0, n_blk * SCALE_BLOCK - n_rows))
+        return flat.reshape(n_blk * SCALE_ROWS, LANES)
+    ksf, vsf = plane(k_scales), plane(v_scales)
 
     def page_map(bh, j, table, lens):
         b = bh // Hkv
@@ -192,7 +214,7 @@ def paged_attention_quant(q: jax.Array, k_pages: jax.Array,
     def scale_map(bh, j, table, lens):
         b = bh // Hkv
         h = bh % Hkv
-        return (table[b, j] * Hkv + h, 0)
+        return ((table[b, j] * Hkv + h) // SCALE_BLOCK, 0)
 
     kernel = functools.partial(_kernel_quant, page=page,
                                n_pages_per_seq=pps, scale=scale, G=G,
@@ -204,8 +226,8 @@ def paged_attention_quant(q: jax.Array, k_pages: jax.Array,
             pl.BlockSpec((1, G, d), lambda bh, j, *_: (bh, 0, 0)),
             pl.BlockSpec((1, page, d), page_map),
             pl.BlockSpec((1, page, d), page_map),
-            pl.BlockSpec((1, LANES), scale_map),
-            pl.BlockSpec((1, LANES), scale_map),
+            pl.BlockSpec((SCALE_ROWS, LANES), scale_map),
+            pl.BlockSpec((SCALE_ROWS, LANES), scale_map),
         ],
         out_specs=pl.BlockSpec((1, G, d), lambda bh, j, *_: (bh, 0, 0)),
         scratch_shapes=[

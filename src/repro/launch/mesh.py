@@ -17,56 +17,31 @@ DATA_AXIS = "data"    # doubles as the FSDP axis
 MODEL_AXIS = "model"  # tensor-parallel axis
 
 
-def _make_mesh(shape: tuple[int, ...], axes: tuple[str, ...]):
-    """jax.make_mesh with explicit Auto axis types where the installed jax
-    supports them (axis_types landed after 0.4.37; Auto is the default
-    behavior on older versions, so dropping the kwarg is equivalent)."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is not None:
-        return jax.make_mesh(shape, axes,
-                             axis_types=(axis_type.Auto,) * len(axes))
-    return jax.make_mesh(shape, axes)
-
-
-def shard_map(f, *, mesh, in_specs, out_specs, check_vma: bool = False,
-              axis_names=None):
-    """jax.shard_map across jax versions: older releases only ship
-    jax.experimental.shard_map.shard_map, with check_rep instead of
-    check_vma and the manual-axes set expressed as its complement (auto)."""
-    if hasattr(jax, "shard_map"):
-        kw = {} if axis_names is None else {"axis_names": axis_names}
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=check_vma, **kw)
-    from jax.experimental.shard_map import shard_map as _shard_map
-    kw = {}
-    if axis_names is not None:
-        kw["auto"] = frozenset(mesh.axis_names) - frozenset(axis_names)
-    return _shard_map(f, mesh=mesh, in_specs=in_specs,
-                      out_specs=out_specs, check_rep=check_vma, **kw)
-
-
 def make_production_mesh(*, multi_pod: bool = False):
     """The production mesh: 16x16 single pod, or 2x16x16 across two pods."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = (POD_AXIS, DATA_AXIS, MODEL_AXIS) if multi_pod else (DATA_AXIS, MODEL_AXIS)
-    return _make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
-def make_mesh(shape: tuple[int, ...], axes: tuple[str, ...]):
-    """Arbitrary mesh helper (used by tests and the elastic runtime)."""
-    if int(np.prod(shape)) > len(jax.devices()):
-        raise ValueError(
-            f"mesh {shape} needs {int(np.prod(shape))} devices, "
-            f"have {len(jax.devices())}"
-        )
-    return _make_mesh(shape, axes)
+def make_mesh(shape: tuple[int, ...], axes: tuple[str, ...], devices=None):
+    """A mesh of Auto axes over the first prod(shape) of ``devices``
+    (default: all local devices)."""
+    devices = list(jax.devices() if devices is None else devices)
+    n = int(np.prod(shape))
+    if n > len(devices):
+        raise ValueError(f"mesh {shape} needs {n} devices, "
+                         f"have {len(devices)}")
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes),
+                         devices=devices[:n])
 
 
 def make_host_mesh(model_parallel: int = 1):
     """A mesh over whatever devices exist locally (smoke tests, examples)."""
     n = len(jax.devices())
     dp = max(1, n // model_parallel)
-    return _make_mesh((dp, model_parallel), (DATA_AXIS, MODEL_AXIS))
+    return make_mesh((dp, model_parallel), (DATA_AXIS, MODEL_AXIS))
 
 
 def mesh_axis_names(mesh) -> tuple[str, ...]:

@@ -8,6 +8,8 @@ mode whose win the cost model predicts via `overlap`).
   PYTHONPATH=src python -m repro.launch.serve --arch yi-9b --reduced \
       --requests 4 --prompt 64 --gen 32 [--offload-weights]
 
+Without ``--reduced`` the configuration runs at its published widths.
+
 ``DecodeScheduler`` is the deadline-aware decode loop over a tier-split
 ``PagedKVCache``: it plans host->HBM page prefetches through the fabric
 simulator and admits each sequence into the decode batch at the first step
@@ -42,6 +44,7 @@ import numpy as np
 
 from repro.config.base import ParallelConfig, get_config
 from repro.core.offload import put_tree
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_host_mesh
 from repro.models.model import Model
 from repro.obs.trace import NULL_TRACER
@@ -61,6 +64,7 @@ class Result:
     tokens: list
     prefill_ms: float
     decode_ms_per_tok: float
+    logits: Optional[np.ndarray] = None   # (max_new, vocab) f32 if kept
 
 
 class ServeEngine:
@@ -80,18 +84,22 @@ class ServeEngine:
         self.straggler = StragglerStats()
         mesh = mesh or make_host_mesh()
         self.model = Model.create(cfg, mesh, parallel)
-        params = self.model.init(jax.random.key(rng_seed))
-        params = jax.tree.map(lambda p: p.astype(jnp.bfloat16), params)
+        params = self.model.init(jax.random.key(rng_seed),
+                                 dtype=jnp.bfloat16)
         self.offload = offload_weights
         if offload_weights:
             self.params_home = put_tree(params, "pinned_host")
         else:
             self.params_home = params
+        # the jitted closures capture the model, not the engine: a cycle
+        # through self would keep the weights alive after the engine is
+        # dropped, until the garbage collector happened to run
+        model = self.model
         self._prefill = jax.jit(
-            lambda p, b, n: self.model.prefill(p, b, max_len=n),
+            lambda p, b, n: model.prefill(p, b, max_len=n),
             static_argnums=(2,))
         self._decode = jax.jit(
-            lambda p, c, t, i: self.model.decode(p, c, t, i),
+            lambda p, c, t, i: model.decode(p, c, t, i),
             donate_argnums=(1,))
 
     def _params(self):
@@ -99,6 +107,15 @@ class ServeEngine:
         if self.offload:
             return put_tree(self.params_home, "device")
         return self.params_home
+
+    def compile_prefill(self, requests: list[Request]):
+        """The compiled prefill program this batch of requests runs (for
+        inspecting what the compiler made of it, e.g. its kernels)."""
+        toks = prompt_batch(requests)
+        max_new = max(r.max_new for r in requests)
+        return self._prefill.lower(self._params(),
+                                   {"tokens": jnp.asarray(toks)},
+                                   toks.shape[1] + max_new).compile()
 
     def prefill(self, requests: list[Request]) -> "PrefillHandoff":
         """The prefill role: run the prompt pass and hand off everything
@@ -112,10 +129,8 @@ class ServeEngine:
         """
         B = len(requests)
         tracer = self.tracer
-        plen = max(len(r.prompt) for r in requests)
-        toks = np.zeros((B, plen), np.int32)
-        for i, r in enumerate(requests):
-            toks[i, plen - len(r.prompt):] = r.prompt   # left-pad
+        toks = prompt_batch(requests)
+        plen = toks.shape[1]
         if tracer.enabled:
             for r in requests:
                 tracer.instant("serve.admit", track=("serving", "engine"),
@@ -133,15 +148,21 @@ class ServeEngine:
             jax.block_until_ready(tok)
         prefill_ms = (time.perf_counter() - t0) * 1e3
         return PrefillHandoff(requests, cache, tok, plen, max_new,
-                              prefill_ms)
+                              prefill_ms, logits)
 
-    def decode(self, handoff: "PrefillHandoff") -> list[Result]:
-        """The decode role: step the handed-off KV cache to completion."""
+    def decode(self, handoff: "PrefillHandoff",
+               keep_logits: bool = False) -> list[Result]:
+        """The decode role: step the handed-off KV cache to completion.
+
+        ``keep_logits`` copies each step's logits to the host into
+        ``Result.logits`` (for checks against a reference; it costs a
+        device read per step)."""
         requests = handoff.requests
         B = len(requests)
         tracer = self.tracer
         cache, tok = handoff.cache, handoff.tok
         outs = [[] for _ in requests]
+        kept = []
         t0 = time.perf_counter()
         for s in range(handoff.max_new):
             ts = time.perf_counter()
@@ -154,6 +175,8 @@ class ServeEngine:
                 tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
                 # one device read for the whole batch, not B scalar reads
                 tok_host = np.asarray(tok)
+                if keep_logits:
+                    kept.append(np.asarray(logits[:, 0], np.float32))
             # per-step wall time feeds the straggler detector: sustained
             # p95/median inflation is the elastic layer's degrade signal
             self.straggler.record(time.perf_counter() - ts)
@@ -175,13 +198,24 @@ class ServeEngine:
             for r in requests:
                 self.slo.observe("serve", lat)
         return [Result(r.rid, outs[i][:r.max_new], handoff.prefill_ms,
-                       ms_per_tok)
+                       ms_per_tok,
+                       np.stack([s[i] for s in kept])[:r.max_new]
+                       if keep_logits else None)
                 for i, r in enumerate(requests)]
 
     def serve(self, requests: list[Request]) -> list[Result]:
         """Monolithic serving: prefill role then decode role, in-process
         (the synchronous-handoff special case of disaggregation)."""
         return self.decode(self.prefill(requests))
+
+
+def prompt_batch(requests: list[Request]) -> np.ndarray:
+    """(B, longest prompt) int32 token batch, prompts left-padded with 0."""
+    plen = max(len(r.prompt) for r in requests)
+    toks = np.zeros((len(requests), plen), np.int32)
+    for i, r in enumerate(requests):
+        toks[i, plen - len(r.prompt):] = r.prompt
+    return toks
 
 
 @dataclasses.dataclass
@@ -195,6 +229,7 @@ class PrefillHandoff:
     plen: int                    # padded prompt length (step offset base)
     max_new: int
     prefill_ms: float
+    logits: jax.Array            # (B, 1, vocab) last prompt position
 
 
 # --------------------------------------------------------------------------
@@ -535,7 +570,10 @@ def simulate_paged_decode(*, requests: int = 8, prompt: int = 1024,
 def main():
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="yi-9b")
-    ap.add_argument("--reduced", action="store_true", default=True)
+    ap.add_argument("--reduced", action=argparse.BooleanOptionalAction,
+                    default=False,
+                    help="run the configuration's tiny CPU-test variant "
+                         "instead of its published widths")
     ap.add_argument("--requests", type=int, default=4)
     ap.add_argument("--prompt", type=int, default=64)
     ap.add_argument("--gen", type=int, default=32)
@@ -596,6 +634,7 @@ def main():
                          "re-probe + refit + hot-swap (needs "
                          "--calibration-profile)")
     args = ap.parse_args()
+    enable_compile_cache()
 
     tracer = NULL_TRACER
     if args.trace_out or args.metrics_out or args.openmetrics_out \

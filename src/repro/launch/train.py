@@ -28,6 +28,7 @@ from repro.config.base import (ParallelConfig, RunConfig, ShapeConfig,
 from repro.checkpoint.manager import CheckpointManager
 from repro.core.placement import plan_training_placement
 from repro.data.synthetic import PrefetchLoader, synthetic_batch
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_host_mesh, num_chips
 from repro.models.model import Model
 from repro.optim import adamw, schedule
@@ -105,6 +106,7 @@ def main():
     ap.add_argument("--ckpt-dir", default="/tmp/repro_ckpt")
     ap.add_argument("--microbatches", type=int, default=1)
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = get_config(args.arch)
     if args.reduced:

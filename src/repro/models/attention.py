@@ -175,17 +175,41 @@ def attn_forward(p: dict, x: jax.Array, positions: jax.Array,
             and q.shape[1] == k.shape[1]):
         # TPU hot-spot path: the Pallas flash kernel (repro.kernels).
         # Semantics == chunked_attention (tests/test_kernels.py).
-        from repro.kernels.flash_attention import flash_attention
-        ctx = flash_attention(
-            q.transpose(0, 2, 1, 3), k.transpose(0, 2, 1, 3),
-            v.transpose(0, 2, 1, 3), causal=causal, window=window,
-            q_blk=min(512, q.shape[1]), kv_blk=min(512, k.shape[1]),
-        ).transpose(0, 2, 1, 3)
+        ctx = _flash_attention(q, k, v, mctx, causal=causal, window=window)
     else:
         ctx = chunked_attention(q, k, v, causal=causal, window=window,
                                 q_chunk=q_chunk)
     out = jnp.einsum("bshk,hkd->bsd", ctx, p["w_o"].astype(ctx.dtype))
     return out, {"k": k, "v": v}
+
+
+def _flash_attention(q, k, v, mctx, *, causal: bool, window: int):
+    """The flash kernel over (B, S, H, dh) q/k/v. Mosaic kernels are not
+    partitioned automatically, so on a mesh of several devices each one
+    runs the kernel on its own batch rows and heads under shard_map."""
+    from repro.kernels.flash_attention import flash_attention
+    from repro.models.sharding import spec_for
+
+    def call(q, k, v):
+        return flash_attention(q, k, v, causal=causal, window=window,
+                               q_blk=min(512, q.shape[2]),
+                               kv_blk=min(512, k.shape[2]))
+
+    qt, kt, vt = (a.transpose(0, 2, 1, 3) for a in (q, k, v))
+    mesh = mctx.mesh
+    if mesh.size == 1:
+        return call(qt, kt, vt).transpose(0, 2, 1, 3)
+    axes = ("act_batch", "act_heads", None, None)
+    q_spec = spec_for(axes, mctx.rules, qt.shape, mesh)
+    kv_spec = spec_for(axes, mctx.rules, kt.shape, mesh)
+    if tuple(q_spec)[1:2] != tuple(kv_spec)[1:2]:
+        raise ValueError(
+            f"flash attention on mesh {dict(mesh.shape)}: {qt.shape[1]} "
+            f"query heads and {kt.shape[1]} kv heads split differently "
+            f"({q_spec} vs {kv_spec})")
+    out = jax.shard_map(call, mesh=mesh, in_specs=(q_spec, kv_spec, kv_spec),
+                        out_specs=q_spec, check_vma=False)(qt, kt, vt)
+    return out.transpose(0, 2, 1, 3)
 
 
 def attn_decode(p: dict, x: jax.Array, pos, cache: dict,

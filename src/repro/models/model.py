@@ -64,8 +64,20 @@ class Model:
         return _tree_map_with_path(mk, cspecs)
 
     # -- init ---------------------------------------------------------------
-    def init(self, rng) -> dict:
-        return pm.init_params(self.specs, rng)
+    def init(self, rng, dtype=None) -> dict:
+        """Parameters from ``rng``, made inside one jit: each leaf is drawn
+        directly in ``dtype`` (default: its spec's) with its own sharding,
+        so every shard is created on its own device and no float32 copy of
+        a bf16 model exists anywhere."""
+        specs = self.specs
+        if dtype is not None:
+            specs = jax.tree.map(
+                lambda s: dataclasses.replace(s, dtype=jnp.dtype(dtype).name),
+                specs, is_leaf=lambda x: isinstance(x, pm.ParamSpec))
+        shardings = jax.tree.map(lambda a: a.sharding,
+                                 self.abstract_params(dtype=dtype))
+        return jax.jit(lambda key: pm.init_params(specs, key),
+                       out_shardings=shardings)(rng)
 
     def init_cache(self, B: int, S: int) -> dict:
         cspecs = cache_specs(self.cfg, self.mctx, B, S)

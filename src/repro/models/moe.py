@@ -119,24 +119,16 @@ def _expert_ffn(toks, w_gate, w_up, w_down):
 # --------------------------------------------------------------------------
 
 
-def _axis_size(name: str) -> int:
-    """Mesh axis size inside shard_map, portable across jax versions
-    (lax.axis_size is newer; psum(1, axis) is the classic spelling)."""
-    if hasattr(jax.lax, "axis_size"):
-        return jax.lax.axis_size(name)
-    return jax.lax.psum(1, name)
-
-
 def _moe_ep_body(x, router_w, w_gate, w_up, w_down, *, cfg: ModelConfig,
                  group_axes: tuple[str, ...], tp_axis: str,
                  all_axes: tuple[str, ...]):
     e = cfg.moe
     E = e.num_experts
     B, S, d = x.shape
-    tp = _axis_size(tp_axis)
+    tp = jax.lax.axis_size(tp_axis)
     G = 1
     for a in group_axes:
-        G *= _axis_size(a)
+        G *= jax.lax.axis_size(a)
     E_loc = E // G
     T_loc = B * S
     x_tok = x.reshape(T_loc, d)
@@ -267,9 +259,8 @@ def moe_ffn(p: dict, x: jax.Array, cfg: ModelConfig, mctx) -> tuple:
                     P(None, fsdp, "model"),
                     P(None, "model", fsdp))
 
-    from repro.launch.mesh import shard_map
-    fn = shard_map(body, mesh=mesh, in_specs=in_specs,
-                   out_specs=(x_spec, P()), check_vma=False)
+    fn = jax.shard_map(body, mesh=mesh, in_specs=in_specs,
+                       out_specs=(x_spec, P()), check_vma=False)
     y, aux = fn(x, p["router"], p["w_gate"], p["w_up"], p["w_down"])
 
     if e.num_shared_experts:

@@ -20,6 +20,7 @@ Logical axis vocabulary (mapped to mesh axes in ``repro.models.sharding``):
 from __future__ import annotations
 
 import dataclasses
+import zlib
 from typing import Optional
 
 import jax
@@ -82,12 +83,17 @@ def _flatten_with_path(tree, prefix=()):
 
 
 def init_params(specs, rng):
-    """Initialize a param pytree from a spec tree, path-deterministic."""
+    """Initialize a param pytree from a spec tree, path-deterministic.
+
+    Each leaf's key folds in a stable hash of its path (crc32, not Python's
+    per-process salted ``hash``), so one seed gives the same parameters in
+    every process."""
     def build(tree, prefix=()):
         if isinstance(tree, ParamSpec):
             key = rng
             for p in prefix:
-                key = jax.random.fold_in(key, hash(p) % (2**31))
+                key = jax.random.fold_in(
+                    key, zlib.crc32(p.encode()) % (2**31))
             return _init_one(tree, key)
         return {k: build(v, prefix + (k,)) for k, v in tree.items()}
     return build(specs)

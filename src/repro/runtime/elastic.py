@@ -23,7 +23,7 @@ from types import SimpleNamespace
 from typing import Optional, Sequence
 
 from repro.config.base import ModelConfig, ShapeConfig
-from repro.launch.mesh import DATA_AXIS, MODEL_AXIS, _make_mesh
+from repro.launch.mesh import DATA_AXIS, MODEL_AXIS, make_mesh
 
 
 @dataclasses.dataclass
@@ -64,7 +64,7 @@ def replan(cfg: ModelConfig, shape: ShapeConfig, n_devices: int,
 
 def make_elastic_mesh(decision: ElasticDecision):
     data, model = decision.mesh_shape
-    return _make_mesh((data, model), (DATA_AXIS, MODEL_AXIS))
+    return make_mesh((data, model), (DATA_AXIS, MODEL_AXIS))
 
 
 # --------------------------------------------------------------------------

@@ -81,30 +81,15 @@ class PagedKVCache:
         self.tier_of_page = interleave_pages(cfg.n_pages, list(cfg.weights))
         self.k_pool = place(jnp.zeros(shape, dt), "hbm")
         self.v_pool = place(jnp.zeros(shape, dt), "hbm")
-        # host-resident shadow for pages assigned to the host tier;
-        # _host_idx is the gather/scatter index list spill/fetch move by
-        # (only those rows, not a full-pool where-merge)
+        # host-tier pages: their backing moves to a compact host shadow,
+        # one row per host-tier page in _host_idx order, replaced whole by
+        # every spill (None until the first spill, released by the fetch)
         self._host_mask = self.tier_of_page == 1
         self._host_idx = np.nonzero(self._host_mask)[0]
-        if self._host_mask.any():
-            if cfg.kv_dtype == "int8":
-                sshape = (cfg.n_pages, cfg.kv_heads)
-                self.k_pool_host = place(jnp.zeros(shape, jnp.int8), "host")
-                self.v_pool_host = place(jnp.zeros(shape, jnp.int8), "host")
-                self.k_scales_host = place(
-                    jnp.zeros(sshape, jnp.float32), "host")
-                self.v_scales_host = place(
-                    jnp.zeros(sshape, jnp.float32), "host")
-            else:
-                self.k_pool_host = place(jnp.zeros(shape, dt), "host")
-                self.v_pool_host = place(jnp.zeros(shape, dt), "host")
+        self._drop_shadow()
         self.free = collections.deque(range(cfg.n_pages))
         self.tables: dict[int, list[int]] = {}    # seq id -> page ids
         self.lens: dict[int, int] = {}
-        # host shadow is only valid after spill_cold_pages populated it;
-        # fetching before any spill would overwrite live HBM pages with the
-        # zero-initialized shadow (silent KV corruption)
-        self._spilled = False
         # block_table/seq_lens cache, keyed by the seq-id tuple; one decode
         # step calls attend once per layer, so rebuilding the padded numpy
         # table per call is pure overhead — invalidated on any table change
@@ -158,7 +143,7 @@ class PagedKVCache:
         self._quant_pools = None
         # the HBM pool is the live copy again; any host shadow is stale —
         # a fetch_spilled without a fresh spill must not clobber this write
-        self._spilled = False
+        self._drop_shadow()
         if self.tracer.enabled:
             elem = jnp.dtype(self.cfg.dtype).itemsize
             self.tracer.metrics.add("pager.append.tokens", T)
@@ -237,6 +222,13 @@ class PagedKVCache:
                                      interpret=interpret)
 
     # -- tier maintenance -----------------------------------------------------
+    def _drop_shadow(self) -> None:
+        """Forget the host shadow: it is stale (or consumed), so a later
+        fetch must not write it back over the live HBM pool."""
+        self._spilled = False
+        self.k_pool_host = self.v_pool_host = None
+        self.k_scales_host = self.v_scales_host = None
+
     def spill_cold_pages(self) -> int:
         """Move host-tier-assigned pages' backing to host memory (the
         paper's cold-page demotion, TPP-style). With ``kv_dtype="int8"``
@@ -247,29 +239,22 @@ class PagedKVCache:
         n_spilled = int(self._host_mask.sum())
         with self.tracer.span("pager.spill", track=("pager", "tiers"),
                               cat="pager", pages=n_spilled):
-            # gather only the host-assigned rows — a full-pool
-            # jnp.where temporary would copy (and with int8, quantize)
-            # every HBM page just to move a few cold ones
+            # gather (and with int8, quantize) only the host-assigned rows
+            # in device memory; the one device_put is the only op that
+            # crosses into host memory — no op mixes the two spaces
             idx = jnp.asarray(self._host_idx)
             k_cold = jnp.take(self.k_pool, idx, axis=0)
             v_cold = jnp.take(self.v_pool, idx, axis=0)
+            host = self.k_pool.sharding.with_memory_kind("pinned_host")
             if self.cfg.kv_dtype == "int8":
                 from repro.kernels.quant import quantize_pages
                 kq, ks = quantize_pages(k_cold)
                 vq, vs = quantize_pages(v_cold)
-                self.k_pool_host = place(
-                    self.k_pool_host.at[idx].set(kq), "host")
-                self.v_pool_host = place(
-                    self.v_pool_host.at[idx].set(vq), "host")
-                self.k_scales_host = place(
-                    self.k_scales_host.at[idx].set(ks), "host")
-                self.v_scales_host = place(
-                    self.v_scales_host.at[idx].set(vs), "host")
+                (self.k_pool_host, self.v_pool_host, self.k_scales_host,
+                 self.v_scales_host) = jax.device_put((kq, vq, ks, vs), host)
             else:
-                self.k_pool_host = place(
-                    self.k_pool_host.at[idx].set(k_cold), "host")
-                self.v_pool_host = place(
-                    self.v_pool_host.at[idx].set(v_cold), "host")
+                self.k_pool_host, self.v_pool_host = jax.device_put(
+                    (k_cold, v_cold), host)
         self._spilled = True
         self.tracer.metrics.add("pager.spill.pages", n_spilled, tier="host")
         self.tracer.metrics.add("pager.spill.bytes",
@@ -283,8 +268,8 @@ class PagedKVCache:
         pages cross the link compressed and dequantize on the HBM side.
 
         No-op until ``spill_cold_pages`` has actually populated the host
-        shadow: a spurious fetch must not overwrite live HBM pages with the
-        zero-initialized shadow. The shadow is consumed by the fetch — it
+        shadow: a spurious fetch must not overwrite live HBM pages with a
+        stale shadow. The shadow is consumed by the fetch — it
         goes stale the moment the live pool is appended to, so a fresh
         spill is required before the next fetch.
         """
@@ -293,28 +278,24 @@ class PagedKVCache:
         n_pages = int(self._host_mask.sum())
         with self.tracer.span("pager.fetch", track=("pager", "tiers"),
                               cat="pager", pages=n_pages):
-            # gather only the spilled rows from the host shadow, move just
-            # those across the link, and scatter them back into the pool
+            # the compact shadow crosses the link whole (one device_put),
+            # then dequantizes and scatters back in device memory
             idx = jnp.asarray(self._host_idx)
+            dev = self.k_pool.sharding
             if self.cfg.kv_dtype == "int8":
                 from repro.kernels.quant import dequantize_pages
-                kq = place(jnp.take(self.k_pool_host, idx, axis=0), "hbm")
-                vq = place(jnp.take(self.v_pool_host, idx, axis=0), "hbm")
-                ks = place(jnp.take(self.k_scales_host, idx, axis=0),
-                           "hbm")
-                vs = place(jnp.take(self.v_scales_host, idx, axis=0),
-                           "hbm")
+                kq, vq, ks, vs = jax.device_put(
+                    (self.k_pool_host, self.v_pool_host,
+                     self.k_scales_host, self.v_scales_host), dev)
                 k_h = dequantize_pages(kq, ks, out_dtype=self.k_pool.dtype)
                 v_h = dequantize_pages(vq, vs, out_dtype=self.v_pool.dtype)
             else:
-                k_h = place(jnp.take(self.k_pool_host, idx, axis=0),
-                            "hbm")
-                v_h = place(jnp.take(self.v_pool_host, idx, axis=0),
-                            "hbm")
+                k_h, v_h = jax.device_put(
+                    (self.k_pool_host, self.v_pool_host), dev)
             self.k_pool = self.k_pool.at[idx].set(k_h)
             self.v_pool = self.v_pool.at[idx].set(v_h)
+        self._drop_shadow()
         self._quant_pools = None
-        self._spilled = False
         self.tracer.metrics.add("pager.fetch.pages", n_pages, tier="host")
         self.tracer.metrics.add("pager.fetch.bytes",
                                 n_pages * self.host_page_bytes,
@@ -349,27 +330,9 @@ class PagedKVCache:
             self.tier_of_page = new_assign
             self._host_mask = new_assign == 1
             self._host_idx = np.nonzero(self._host_mask)[0]
-            if self._host_mask.any() and not hasattr(self, "k_pool_host"):
-                shape = (self.cfg.n_pages, self.cfg.page_size,
-                         self.cfg.kv_heads, self.cfg.head_dim)
-                if self.cfg.kv_dtype == "int8":
-                    sshape = (self.cfg.n_pages, self.cfg.kv_heads)
-                    self.k_pool_host = place(
-                        jnp.zeros(shape, jnp.int8), "host")
-                    self.v_pool_host = place(
-                        jnp.zeros(shape, jnp.int8), "host")
-                    self.k_scales_host = place(
-                        jnp.zeros(sshape, jnp.float32), "host")
-                    self.v_scales_host = place(
-                        jnp.zeros(sshape, jnp.float32), "host")
-                else:
-                    dt = jnp.dtype(self.cfg.dtype)
-                    self.k_pool_host = place(jnp.zeros(shape, dt), "host")
-                    self.v_pool_host = place(jnp.zeros(shape, dt), "host")
         self.cfg = dataclasses.replace(self.cfg, weights=tuple(weights))
         self._bt_cache.clear()
         self._quant_pools = None
-        self._spilled = False
         if self.tracer.enabled:
             m = self.tracer.metrics
             m.add("pager.retier.pages_to_fast", to_fast)

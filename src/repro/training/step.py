@@ -65,12 +65,11 @@ def compute_grads(model: Model, params_c, batch,
         parts = jax.tree.map(lambda x: jax.lax.pmean(x, POD_AXIS), parts)
         return (loss, parts), grads
 
-    from repro.launch.mesh import shard_map
-    fn = shard_map(body, mesh=mesh,
-                   in_specs=(P(), _batch_pod_specs(batch)),
-                   out_specs=((P(), P()), P()),
-                   axis_names=frozenset({POD_AXIS}),
-                   check_vma=False)
+    fn = jax.shard_map(body, mesh=mesh,
+                       in_specs=(P(), _batch_pod_specs(batch)),
+                       out_specs=((P(), P()), P()),
+                       axis_names=frozenset({POD_AXIS}),
+                       check_vma=False)
     return fn(params_c, batch)
 
 

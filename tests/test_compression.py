@@ -67,9 +67,8 @@ def test_compressed_pod_mean_single_axis():
     mesh = make_mesh((1,), ("pod",))
     x = jnp.asarray(np.random.default_rng(0).normal(size=(512,)),
                     jnp.float32)
-    from repro.launch.mesh import shard_map
-    fn = shard_map(partial(compressed_pod_mean, pod_axis="pod"),
-                   mesh=mesh, in_specs=P(), out_specs=P(),
-                   check_vma=False)
+    fn = jax.shard_map(partial(compressed_pod_mean, pod_axis="pod"),
+                       mesh=mesh, in_specs=P(), out_specs=P(),
+                       check_vma=False)
     # int8 error bound: absmax/127/2 ~ 1.4e-2 for N(0,1) extremes
     np.testing.assert_allclose(np.asarray(fn(x)), np.asarray(x), atol=3e-2)
