@@ -34,6 +34,7 @@ from __future__ import annotations
 
 import argparse
 import dataclasses
+import gc
 import json
 import time
 from typing import Optional
@@ -73,15 +74,18 @@ class ServeEngine:
                  offload_weights: bool = False, rng_seed: int = 0,
                  tracer=NULL_TRACER, slo=None):
         self.cfg = cfg
-        # Observability: wall-clock prefill/decode-step spans plus a
-        # StragglerStats fed one sample per decode step — its inflation
-        # flag and summary land in the metrics snapshot, the signal the
-        # elastic-degradation loop will key on. ``slo`` optionally attaches
+        # Observability: wall-clock prefill/decode-step spans plus, while
+        # the tracer is enabled, a StragglerStats fed one sample per decode
+        # step — its inflation flag and summary land in the metrics
+        # snapshot, the signal the elastic-degradation loop will key on.
+        # Every span, and each host phase of a call (``_phase``), also
+        # lands in the JAX profiler's trace. ``slo`` optionally attaches
         # a repro.obs.SLOMonitor: one latency observation per finished
         # request (class "serve"), burn-rate alerting included.
         self.tracer = tracer
         self.slo = slo
         self.straggler = StragglerStats()
+        _install_gc_spans()
         mesh = mesh or make_host_mesh()
         self.model = Model.create(cfg, mesh, parallel)
         params = self.model.init(jax.random.key(rng_seed),
@@ -93,14 +97,18 @@ class ServeEngine:
             self.params_home = params
         # the jitted closures capture the model, not the engine: a cycle
         # through self would keep the weights alive after the engine is
-        # dropped, until the garbage collector happened to run
+        # dropped, until the garbage collector happened to run. Their names
+        # name the programs in the profiler's trace.
         model = self.model
-        self._prefill = jax.jit(
-            lambda p, b, n: model.prefill(p, b, max_len=n),
-            static_argnums=(2,))
-        self._decode = jax.jit(
-            lambda p, c, t, i: model.decode(p, c, t, i),
-            donate_argnums=(1,))
+
+        def serve_prefill(p, b, n):
+            return model.prefill(p, b, max_len=n)
+
+        def serve_decode_step(p, c, t, i):
+            return model.decode(p, c, t, i)
+
+        self._prefill = jax.jit(serve_prefill, static_argnums=(2,))
+        self._decode = jax.jit(serve_decode_step, donate_argnums=(1,))
 
     def _params(self):
         """Paper-faithful sync fetch when offloaded (copy-on-demand)."""
@@ -129,8 +137,7 @@ class ServeEngine:
         """
         B = len(requests)
         tracer = self.tracer
-        toks = prompt_batch(requests)
-        plen = toks.shape[1]
+        plen = max(len(r.prompt) for r in requests)
         if tracer.enabled:
             for r in requests:
                 tracer.instant("serve.admit", track=("serving", "engine"),
@@ -140,12 +147,15 @@ class ServeEngine:
         max_new = max(r.max_new for r in requests)
         with tracer.span("serve.prefill", track=("serving", "engine"),
                          cat="serve", batch=B, prompt_len=plen):
-            params = self._params()
-            logits, cache = self._prefill(params,
-                                          {"tokens": jnp.asarray(toks)},
-                                          plen + max_new)
-            tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-            jax.block_until_ready(tok)
+            with _phase("serve.inputs"):
+                params = self._params()
+                batch = {"tokens": jnp.asarray(prompt_batch(requests))}
+            with _phase("serve.dispatch"):
+                logits, cache = self._prefill(params, batch, plen + max_new)
+            with _phase("serve.sample"):
+                tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+            with _phase("serve.readback"):
+                jax.block_until_ready(tok)
         prefill_ms = (time.perf_counter() - t0) * 1e3
         return PrefillHandoff(requests, cache, tok, plen, max_new,
                               prefill_ms, logits)
@@ -160,31 +170,41 @@ class ServeEngine:
         requests = handoff.requests
         B = len(requests)
         tracer = self.tracer
+        timed = tracer.enabled        # the straggler's one reader
         cache, tok = handoff.cache, handoff.tok
         outs = [[] for _ in requests]
         kept = []
         t0 = time.perf_counter()
-        for s in range(handoff.max_new):
-            ts = time.perf_counter()
-            with tracer.span("serve.decode_step",
-                             track=("serving", "engine"), cat="serve",
-                             step=s, batch=B):
-                params = self._params()
-                logits, cache = self._decode(params, cache, tok,
-                                             jnp.int32(handoff.plen + s))
-                tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-                # one device read for the whole batch, not B scalar reads
-                tok_host = np.asarray(tok)
-                if keep_logits:
-                    kept.append(np.asarray(logits[:, 0], np.float32))
-            # per-step wall time feeds the straggler detector: sustained
-            # p95/median inflation is the elastic layer's degrade signal
-            self.straggler.record(time.perf_counter() - ts)
-            for i in range(B):
-                outs[i].append(int(tok_host[i, 0]))
-        jax.block_until_ready(tok)
+        with _phase("serve.decode", batch=B, steps=handoff.max_new):
+            for s in range(handoff.max_new):
+                with tracer.span("serve.decode_step",
+                                 track=("serving", "engine"), cat="serve",
+                                 step=s, batch=B):
+                    if timed:
+                        ts = time.perf_counter()
+                    with _phase("serve.inputs"):
+                        params = self._params()
+                        pos = jnp.int32(handoff.plen + s)
+                    with _phase("serve.dispatch"):
+                        logits, cache = self._decode(params, cache, tok, pos)
+                    with _phase("serve.sample"):
+                        tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+                    with _phase("serve.readback"):
+                        # one device read for the whole batch, not B
+                        # scalar reads
+                        tok_host = np.asarray(tok)
+                        if keep_logits:
+                            kept.append(np.asarray(logits[:, 0], np.float32))
+                    if timed:
+                        # sustained p95/median inflation of the step time
+                        # is the elastic layer's degrade signal
+                        self.straggler.record(time.perf_counter() - ts)
+                    with _phase("serve.emit"):
+                        for i in range(B):
+                            outs[i].append(int(tok_host[i, 0]))
+            jax.block_until_ready(tok)
         ms_per_tok = (time.perf_counter() - t0) * 1e3 / handoff.max_new
-        if tracer.enabled:
+        if timed:
             m = tracer.metrics
             m.add("serve.requests", B)
             m.add("serve.decode_steps", handoff.max_new)
@@ -207,6 +227,36 @@ class ServeEngine:
         """Monolithic serving: prefill role then decode role, in-process
         (the synchronous-handoff special case of disaggregation)."""
         return self.decode(self.prefill(requests))
+
+
+# A host phase of an engine call, on the profiler's clock only: the obs
+# tracer's events stay the spans above. Named after what the host does.
+_phase = jax.profiler.TraceAnnotation
+
+
+class _GcSpans:
+    """``gc.callbacks`` hook: a ``python.gc`` span around each collection
+    of generation 1 or 2 (generation 0's are many and short). The hook is
+    process-wide, as the collector is; a collection never nests in one."""
+
+    def __init__(self):
+        self.open = None
+
+    def __call__(self, phase, info):
+        if phase == "start" and info["generation"] > 0:
+            self.open = _phase("python.gc", generation=info["generation"])
+            self.open.__enter__()
+        elif phase == "stop" and self.open is not None:
+            span, self.open = self.open, None
+            span.__exit__(None, None, None)
+
+
+_GC_SPANS = _GcSpans()
+
+
+def _install_gc_spans():
+    if _GC_SPANS not in gc.callbacks:
+        gc.callbacks.append(_GC_SPANS)
 
 
 def prompt_batch(requests: list[Request]) -> np.ndarray:

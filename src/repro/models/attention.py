@@ -159,27 +159,31 @@ def attn_forward(p: dict, x: jax.Array, positions: jax.Array,
     """Full-sequence attention (train / prefill). Returns (out, kv) where kv
     holds the rope'd k/v for cache construction."""
     x_kv = x if x_kv is None else x_kv
-    q, k, v = _project_qkv(p, x, x_kv, cfg)
-    if mctx is not None:
-        # pin heads to 'model' (TP) — see mlp_apply (§Perf A3)
-        hax = ("act_batch", None, "act_heads", None)
-        q = mctx.constrain(q, hax)
-        k = mctx.constrain(k, hax)
-        v = mctx.constrain(v, hax)
-    if use_rope:
-        q = apply_rope(q, positions, cfg.rope_theta, cfg.mrope)
-        kp = positions if kv_positions is None else kv_positions
-        k = apply_rope(k, kp, cfg.rope_theta, cfg.mrope)
-    if (mctx is not None
-            and mctx.parallel.attention_kernel == "pallas"
-            and q.shape[1] == k.shape[1]):
-        # TPU hot-spot path: the Pallas flash kernel (repro.kernels).
-        # Semantics == chunked_attention (tests/test_kernels.py).
-        ctx = _flash_attention(q, k, v, mctx, causal=causal, window=window)
-    else:
-        ctx = chunked_attention(q, k, v, causal=causal, window=window,
-                                q_chunk=q_chunk)
-    out = jnp.einsum("bshk,hkd->bsd", ctx, p["w_o"].astype(ctx.dtype))
+    with jax.named_scope("qkv"):
+        q, k, v = _project_qkv(p, x, x_kv, cfg)
+        if mctx is not None:
+            # pin heads to 'model' (TP) — see mlp_apply (§Perf A3)
+            hax = ("act_batch", None, "act_heads", None)
+            q = mctx.constrain(q, hax)
+            k = mctx.constrain(k, hax)
+            v = mctx.constrain(v, hax)
+        if use_rope:
+            q = apply_rope(q, positions, cfg.rope_theta, cfg.mrope)
+            kp = positions if kv_positions is None else kv_positions
+            k = apply_rope(k, kp, cfg.rope_theta, cfg.mrope)
+    with jax.named_scope("attend"):
+        if (mctx is not None
+                and mctx.parallel.attention_kernel == "pallas"
+                and q.shape[1] == k.shape[1]):
+            # TPU hot-spot path: the Pallas flash kernel (repro.kernels).
+            # Semantics == chunked_attention (tests/test_kernels.py).
+            ctx = _flash_attention(q, k, v, mctx, causal=causal,
+                                   window=window)
+        else:
+            ctx = chunked_attention(q, k, v, causal=causal, window=window,
+                                    q_chunk=q_chunk)
+    with jax.named_scope("out"):
+        out = jnp.einsum("bshk,hkd->bsd", ctx, p["w_o"].astype(ctx.dtype))
     return out, {"k": k, "v": v}
 
 
@@ -221,30 +225,32 @@ def attn_decode(p: dict, x: jax.Array, pos, cache: dict,
     (window > 0 and cache length == window) entries are written at
     pos % window.
     """
-    q, k_new, v_new = _project_qkv(p, x, x, cfg)
-    B = x.shape[0]
-    positions = jnp.full((B, 1), pos)
-    if cfg.mrope:
-        positions = jnp.broadcast_to(positions, (3, B, 1))
-    if use_rope:
-        q = apply_rope(q, positions, cfg.rope_theta, cfg.mrope)
-        k_new = apply_rope(k_new, positions, cfg.rope_theta, cfg.mrope)
+    with jax.named_scope("qkv"):
+        q, k_new, v_new = _project_qkv(p, x, x, cfg)
+        B = x.shape[0]
+        positions = jnp.full((B, 1), pos)
+        if cfg.mrope:
+            positions = jnp.broadcast_to(positions, (3, B, 1))
+        if use_rope:
+            q = apply_rope(q, positions, cfg.rope_theta, cfg.mrope)
+            k_new = apply_rope(k_new, positions, cfg.rope_theta, cfg.mrope)
     S = cache["k"].shape[1]
-    slot = jnp.where(window > 0, pos % S, pos) if window > 0 else pos
-    k_cache = jax.lax.dynamic_update_slice_in_dim(cache["k"],
-                                                  k_new.astype(cache["k"].dtype),
-                                                  slot, axis=1)
-    v_cache = jax.lax.dynamic_update_slice_in_dim(cache["v"],
-                                                  v_new.astype(cache["v"].dtype),
-                                                  slot, axis=1)
+    with jax.named_scope("kv_update"):
+        slot = jnp.where(window > 0, pos % S, pos) if window > 0 else pos
+        k_cache = jax.lax.dynamic_update_slice_in_dim(
+            cache["k"], k_new.astype(cache["k"].dtype), slot, axis=1)
+        v_cache = jax.lax.dynamic_update_slice_in_dim(
+            cache["v"], v_new.astype(cache["v"].dtype), slot, axis=1)
     if window > 0:
         valid = jnp.arange(S) <= pos           # ring: all valid once wrapped
         valid |= pos >= S
     else:
         valid = jnp.arange(S) <= pos
-    ctx = decode_attention(q, k_cache.astype(q.dtype),
-                           v_cache.astype(q.dtype), valid)
-    out = jnp.einsum("bshk,hkd->bsd", ctx, p["w_o"].astype(ctx.dtype))
+    with jax.named_scope("attend"):
+        ctx = decode_attention(q, k_cache.astype(q.dtype),
+                               v_cache.astype(q.dtype), valid)
+    with jax.named_scope("out"):
+        out = jnp.einsum("bshk,hkd->bsd", ctx, p["w_o"].astype(ctx.dtype))
     return out, {"k": k_cache, "v": v_cache}
 
 

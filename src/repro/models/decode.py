@@ -91,17 +91,20 @@ def _attn_block_dec(p, x, pos, cache, cfg, mctx, *, window, moe,
                     gated=True):
     cache = mctx.constrain_kv(cache)      # keep seq-sharded inside the scan
     h = rmsnorm(x, p["ln1"], cfg.norm_eps)
-    if cfg.attn_type == "mla":
-        a, cache = mla_decode(p["attn"], h, pos, cache, cfg)
-    else:
-        a, cache = attn_decode(p["attn"], h, pos, cache, cfg, window=window)
+    with jax.named_scope("attn"):
+        if cfg.attn_type == "mla":
+            a, cache = mla_decode(p["attn"], h, pos, cache, cfg)
+        else:
+            a, cache = attn_decode(p["attn"], h, pos, cache, cfg,
+                                   window=window)
     cache = mctx.constrain_kv(cache)
     x = x + a
     h2 = rmsnorm(x, p["ln2"], cfg.norm_eps)
     if moe:
         f, _ = moe_ffn(p["moe"], h2, cfg, mctx)
     else:
-        f = mlp_apply(p["mlp"], h2, gated=gated)
+        with jax.named_scope("mlp"):
+            f = mlp_apply(p["mlp"], h2, gated=gated)
     return x + f, cache
 
 
@@ -242,9 +245,11 @@ def prefill(params, cfg: ModelConfig, mctx: MCtx, batch: dict,
                                   q_chunk=q_chunk)
     B, S = x.shape[:2]
     if max_len and max_len > S:
-        caches = _pad_caches_to(caches, cfg, mctx, B, max_len)
-    logits = unembed(params["embed"], x[:, -1:], cfg.tie_embeddings)
-    logits = mctx.constrain(logits, ("act_batch", None, "act_vocab"))
+        with jax.named_scope("attn/kv_update"):
+            caches = _pad_caches_to(caches, cfg, mctx, B, max_len)
+    with jax.named_scope("logits"):
+        logits = unembed(params["embed"], x[:, -1:], cfg.tie_embeddings)
+        logits = mctx.constrain(logits, ("act_batch", None, "act_vocab"))
     return logits, caches
 
 
@@ -292,8 +297,9 @@ def decode_step(params, cfg: ModelConfig, mctx: MCtx, cache: dict,
                 tokens: jax.Array, pos) -> tuple[jax.Array, dict]:
     """One token step. tokens: (B, 1) int32; pos: scalar position."""
     dtype = jnp.dtype(cfg.dtype)
-    x = embed_tokens(params["embed"], tokens, dtype)
-    x = mctx.constrain(x, ("act_batch", None, "act_embed"))
+    with jax.named_scope("embed"):
+        x = embed_tokens(params["embed"], tokens, dtype)
+        x = mctx.constrain(x, ("act_batch", None, "act_embed"))
     new_cache: dict[str, Any] = {}
 
     if cfg.encoder_decoder:
@@ -323,6 +329,7 @@ def decode_step(params, cfg: ModelConfig, mctx: MCtx, cache: dict,
                               cfg, mctx, seg, shared_attn=shared)
             new_cache[seg.name] = c
     x = rmsnorm(x, params["final_norm"], cfg.norm_eps)
-    logits = unembed(params["embed"], x, cfg.tie_embeddings)
-    logits = mctx.constrain(logits, ("act_batch", None, "act_vocab"))
+    with jax.named_scope("logits"):
+        logits = unembed(params["embed"], x, cfg.tie_embeddings)
+        logits = mctx.constrain(logits, ("act_batch", None, "act_vocab"))
     return logits, new_cache

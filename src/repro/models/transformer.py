@@ -190,21 +190,25 @@ def _attn_block_fwd(p, x, positions, cfg: ModelConfig, mctx: MCtx, *,
     sp_out = ("act_batch", "act_seq", "act_embed")
     h = rmsnorm(x, p["ln1"], cfg.norm_eps)
     h = mctx.constrain(h, sp_in)
-    if cfg.attn_type == "mla":
-        a, kv = mla_forward(p["attn"], h, positions, cfg, q_chunk=q_chunk)
-    else:
-        a, kv = attn_forward(p["attn"], h, positions, cfg, causal=causal,
-                             window=window, use_rope=use_rope,
-                             q_chunk=q_chunk, mctx=mctx)
+    with jax.named_scope("attn"):
+        if cfg.attn_type == "mla":
+            a, kv = mla_forward(p["attn"], h, positions, cfg,
+                                q_chunk=q_chunk)
+        else:
+            a, kv = attn_forward(p["attn"], h, positions, cfg,
+                                 causal=causal, window=window,
+                                 use_rope=use_rope, q_chunk=q_chunk,
+                                 mctx=mctx)
     a = mctx.constrain(a, sp_out)
     x = x + a
     h2 = rmsnorm(x, p["ln2"], cfg.norm_eps)
     if moe:
         f, aux = moe_ffn(p["moe"], h2, cfg, mctx)
     else:
-        h2 = mctx.constrain(h2, sp_in)
-        f, aux = mlp_apply(p["mlp"], h2, gated=gated, mctx=mctx), AUX0
-        f = mctx.constrain(f, sp_out)
+        with jax.named_scope("mlp"):
+            h2 = mctx.constrain(h2, sp_in)
+            f, aux = mlp_apply(p["mlp"], h2, gated=gated, mctx=mctx), AUX0
+            f = mctx.constrain(f, sp_out)
     x = x + f
     if not collect:
         kv = None
@@ -269,7 +273,9 @@ def seg_forward(p, x, positions, cfg: ModelConfig, mctx: MCtx, seg: Seg, *,
         def f(carry, p_l):
             x, aux = carry
             x, kv, a = body(p_l, x)
-            return (x, aux + a), _cast_cache(_to_ring(kv, seg.window, S), mctx)
+            with jax.named_scope("attn/kv_update"):
+                kv = _cast_cache(_to_ring(kv, seg.window, S), mctx)
+            return (x, aux + a), kv
         (x, aux), caches = jax.lax.scan(f, (x, AUX0), p)
         return x, caches, aux
 
@@ -395,7 +401,8 @@ def forward_hidden(params, cfg: ModelConfig, mctx: MCtx, batch: dict, *,
                    q_chunk: int = 512):
     """Returns (hidden (B,S,d), caches, aux). Decoder-only archs."""
     dtype = jnp.dtype(cfg.dtype)
-    x = _input_hidden(params, cfg, batch, dtype)
+    with jax.named_scope("embed"):
+        x = _input_hidden(params, cfg, batch, dtype)
     B, S = x.shape[:2]
     positions = _positions(cfg, batch, B, S)
     x = mctx.constrain(x, ("act_batch", "act_seq", "act_embed"))
