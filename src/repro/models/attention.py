@@ -8,6 +8,9 @@ O(S * window).
 
 Decode uses single-token attention against a KV cache; for seq-sharded
 caches (long_500k) XLA partitions the reductions (flash-decoding style).
+``attn_decode`` reads the cache and never writes it: it returns the new
+token's K/V row, and ``models.decode`` writes the rows in place after the
+layers.
 """
 
 from __future__ import annotations
@@ -216,14 +219,50 @@ def _flash_attention(q, k, v, mctx, *, causal: bool, window: int):
     return out.transpose(0, 2, 1, 3)
 
 
+def decode_slot(pos, S: int, window: int = 0):
+    """The cache index a decode step at ``pos`` writes: ``pos % S`` in a
+    ring (window > 0), else ``pos``."""
+    return pos % S if window > 0 else pos
+
+
+def decode_attention_with_row(q: jax.Array, k_cache: jax.Array,
+                              v_cache: jax.Array, cached: jax.Array,
+                              k_new: jax.Array, v_new: jax.Array
+                              ) -> jax.Array:
+    """``decode_attention`` over an unmodified cache plus the new token's row.
+
+    q: (B, 1, Hq, dh); caches: (B, S, Hkv, dh); cached: (S,) the slots that
+    hold earlier tokens; k_new, v_new: (B, 1, Hkv, dh). The new row is one
+    more score column of the same float32 softmax, so this equals
+    ``decode_attention`` over the cache with the row written in. The
+    column is not concatenated to the cache's scores, so a sequence-sharded
+    cache keeps its sharding through the reductions.
+    """
+    B, _, Hq, dh = q.shape
+    Hkv = k_cache.shape[2]
+    G = Hq // Hkv
+    scale = dh ** -0.5
+    qg = q.reshape(B, 1, Hkv, G, dh)
+    scores = _gqa_scores(qg, k_cache) * scale        # (B,Hkv,G,1,S)
+    scores = jnp.where(cached[None, None, None, None, :], scores, NEG_INF)
+    s_new = _gqa_scores(qg, k_new) * scale           # (B,Hkv,G,1,1)
+    m = jnp.maximum(scores.max(-1, keepdims=True), s_new)
+    e, e_new = jnp.exp(scores - m), jnp.exp(s_new - m)
+    total = e.sum(-1, keepdims=True) + e_new
+    ctx = _gqa_ctx(e / total, v_cache) + _gqa_ctx(e_new / total, v_new)
+    return ctx.reshape(B, 1, Hq, dh).astype(q.dtype)
+
+
 def attn_decode(p: dict, x: jax.Array, pos, cache: dict,
                 cfg: ModelConfig, *, window: int = 0,
                 use_rope: bool = True) -> tuple[jax.Array, dict]:
     """One decode step. x: (B, 1, d). cache: {k,v: (B, S_or_W, Hkv, dh)}.
 
-    ``pos`` is the current absolute position (scalar int). For ring caches
-    (window > 0 and cache length == window) entries are written at
-    pos % window.
+    ``pos`` is the current absolute position (scalar int); a ring cache
+    (window > 0 and cache length == window) holds it at pos % window.
+    The cache is only read here: the step attends over the earlier tokens'
+    slots plus its own K/V, and returns (out, {k, v: (B, 1, Hkv, dh)}), the
+    new rows, which the caller writes at ``decode_slot`` after the layers.
     """
     with jax.named_scope("qkv"):
         q, k_new, v_new = _project_qkv(p, x, x, cfg)
@@ -235,23 +274,17 @@ def attn_decode(p: dict, x: jax.Array, pos, cache: dict,
             q = apply_rope(q, positions, cfg.rope_theta, cfg.mrope)
             k_new = apply_rope(k_new, positions, cfg.rope_theta, cfg.mrope)
     S = cache["k"].shape[1]
-    with jax.named_scope("kv_update"):
-        slot = jnp.where(window > 0, pos % S, pos) if window > 0 else pos
-        k_cache = jax.lax.dynamic_update_slice_in_dim(
-            cache["k"], k_new.astype(cache["k"].dtype), slot, axis=1)
-        v_cache = jax.lax.dynamic_update_slice_in_dim(
-            cache["v"], v_new.astype(cache["v"].dtype), slot, axis=1)
-    if window > 0:
-        valid = jnp.arange(S) <= pos           # ring: all valid once wrapped
-        valid |= pos >= S
-    else:
-        valid = jnp.arange(S) <= pos
+    rows = {"k": k_new.astype(cache["k"].dtype),
+            "v": v_new.astype(cache["v"].dtype)}
+    slots = jnp.arange(S)
+    cached = (slots < pos) & (slots != decode_slot(pos, S, window))
     with jax.named_scope("attend"):
-        ctx = decode_attention(q, k_cache.astype(q.dtype),
-                               v_cache.astype(q.dtype), valid)
+        ctx = decode_attention_with_row(
+            q, cache["k"].astype(q.dtype), cache["v"].astype(q.dtype),
+            cached, rows["k"].astype(q.dtype), rows["v"].astype(q.dtype))
     with jax.named_scope("out"):
         out = jnp.einsum("bshk,hkd->bsd", ctx, p["w_o"].astype(ctx.dtype))
-    return out, {"k": k_cache, "v": v_cache}
+    return out, rows
 
 
 def attn_decode_cross(p: dict, x: jax.Array, cross_kv: dict,

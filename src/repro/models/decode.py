@@ -4,6 +4,11 @@ Decode caches mirror the ``collect=True`` structure of the forward pass, so
 prefill output feeds decode directly. For ``long_500k`` the attention caches
 are sequence-sharded over the 'data' mesh axis (``mctx.seq_sharded_cache``)
 and XLA partitions the score/softmax reductions flash-decoding style.
+
+Each layer reads its attention cache once, inside the layer scan, and
+returns only the new token's K/V row; ``write_rows`` writes the stacked
+rows into the (donated) cache after the scan, so a step moves no
+cache-sized buffer. MLA (``mla_decode``) still returns its latent cache.
 """
 
 from __future__ import annotations
@@ -17,7 +22,7 @@ import jax.numpy as jnp
 from repro.config.base import ModelConfig
 from repro.models import kvcache
 from repro.models.attention import (attn_decode, attn_decode_cross,
-                                    mla_decode)
+                                    decode_slot, mla_decode)
 from repro.models.context import MCtx
 from repro.models.layers import (embed_tokens, mlp_apply, rmsnorm,
                                  sinusoidal_pos_emb, unembed)
@@ -89,15 +94,16 @@ def cache_specs(cfg: ModelConfig, mctx: MCtx, B: int, S: int) -> dict:
 
 def _attn_block_dec(p, x, pos, cache, cfg, mctx, *, window, moe,
                     gated=True):
+    """Returns (x, new K/V rows), or (x, whole latent cache) for MLA."""
     cache = mctx.constrain_kv(cache)      # keep seq-sharded inside the scan
     h = rmsnorm(x, p["ln1"], cfg.norm_eps)
     with jax.named_scope("attn"):
         if cfg.attn_type == "mla":
             a, cache = mla_decode(p["attn"], h, pos, cache, cfg)
+            cache = mctx.constrain_kv(cache)
         else:
             a, cache = attn_decode(p["attn"], h, pos, cache, cfg,
                                    window=window)
-    cache = mctx.constrain_kv(cache)
     x = x + a
     h2 = rmsnorm(x, p["ln2"], cfg.norm_eps)
     if moe:
@@ -131,6 +137,18 @@ def _slstm_block_dec(p, x, cache, cfg):
 # --------------------------------------------------------------------------
 
 
+def write_rows(cache: dict, rows: dict, pos, window: int = 0) -> dict:
+    """Write the new K/V rows (..., B, 1, Hkv, dh) into the stacked cache
+    (..., B, S, Hkv, dh) at ``decode_slot``: the step's only cache write,
+    in place when the cache is donated."""
+    def put(c, r):
+        start = [0] * c.ndim
+        start[-3] = decode_slot(pos, c.shape[-3], window)
+        return jax.lax.dynamic_update_slice(c, r, start)
+    with jax.named_scope("attn/kv_update"):
+        return {k: put(cache[k], rows[k]) for k in cache}
+
+
 def seg_decode(p, cache, x, pos, cfg: ModelConfig, mctx: MCtx, seg: Seg,
                shared_attn=None):
     if seg.kind == "attn":
@@ -138,7 +156,10 @@ def seg_decode(p, cache, x, pos, cfg: ModelConfig, mctx: MCtx, seg: Seg,
             p_l, c_l = args
             return _attn_block_dec(p_l, x, pos, c_l, cfg, mctx,
                                    window=seg.window, moe=seg.moe)
-        return jax.lax.scan(f, x, (p, cache))
+        x, new = jax.lax.scan(f, x, (p, cache))
+        if cfg.attn_type == "mla":
+            return x, new
+        return x, write_rows(cache, new, pos, seg.window)
 
     if seg.kind == "gemma":
         def group(x, args):
@@ -148,12 +169,16 @@ def seg_decode(p, cache, x, pos, cfg: ModelConfig, mctx: MCtx, seg: Seg,
                 p_l, c_l = a
                 return _attn_block_dec(p_l, x, pos, c_l, cfg, mctx,
                                        window=seg.window, moe=False)
-            x, local_c = jax.lax.scan(loc, x, (p_g["local"], c_g["local"]))
-            x, global_c = _attn_block_dec(p_g["global"], x, pos,
+            x, local_r = jax.lax.scan(loc, x, (p_g["local"], c_g["local"]))
+            x, global_r = _attn_block_dec(p_g["global"], x, pos,
                                           c_g["global"], cfg, mctx,
                                           window=0, moe=False)
-            return x, {"local": local_c, "global": global_c}
-        return jax.lax.scan(group, x, (p, cache))
+            return x, {"local": local_r, "global": global_r}
+        x, rows = jax.lax.scan(group, x, (p, cache))
+        return x, {"local": write_rows(cache["local"], rows["local"], pos,
+                                       seg.window),
+                   "global": write_rows(cache["global"], rows["global"],
+                                        pos)}
 
     if seg.kind == "zamba":
         sa = shared_attn
@@ -168,12 +193,13 @@ def seg_decode(p, cache, x, pos, cfg: ModelConfig, mctx: MCtx, seg: Seg,
             h = rmsnorm(x, sa["ln1"], cfg.norm_eps)
             a, kv = attn_decode(sa["attn"], h, pos,
                                 mctx.constrain_kv(c_g["attn"]), cfg)
-            kv = mctx.constrain_kv(kv)
             x = x + a
             x = x + mlp_apply(sa["mlp"],
                               rmsnorm(x, sa["ln2"], cfg.norm_eps))
             return x, {"mamba": mcache, "attn": kv}
-        return jax.lax.scan(group, x, (p, cache))
+        x, new = jax.lax.scan(group, x, (p, cache))
+        return x, {"mamba": new["mamba"],
+                   "attn": write_rows(cache["attn"], new["attn"], pos)}
 
     if seg.kind == "mamba":
         def f(x, args):
@@ -312,16 +338,16 @@ def decode_step(params, cfg: ModelConfig, mctx: MCtx, cache: dict,
             a, kv = attn_decode(p_l["attn"], h, pos,
                                 mctx.constrain_kv(c_l["self"]), cfg,
                                 use_rope=False)
-            kv = mctx.constrain_kv(kv)
             x = x + a
             hx = rmsnorm(x, p_l["ln_x"], cfg.norm_eps)
             x = x + attn_decode_cross(p_l["xattn"], hx, c_l["cross"], cfg)
             f_ = mlp_apply(p_l["mlp"],
                            rmsnorm(x, p_l["ln2"], cfg.norm_eps), gated=False)
-            return x + f_, {"self": kv, "cross": c_l["cross"]}
-        x, dec_c = jax.lax.scan(f, x, (params["decoder"],
-                                       cache["decoder"]))
-        new_cache["decoder"] = dec_c
+            return x + f_, kv
+        dec = cache["decoder"]
+        x, rows = jax.lax.scan(f, x, (params["decoder"], dec))
+        new_cache["decoder"] = {"self": write_rows(dec["self"], rows, pos),
+                                "cross": dec["cross"]}
     else:
         shared = params.get("shared_attn")
         for seg in segment_plan(cfg):

@@ -5,7 +5,8 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.models.attention import chunked_attention, decode_attention
+from repro.models.attention import (chunked_attention, decode_attention,
+                                    decode_attention_with_row, decode_slot)
 
 
 def naive(q, k, v, causal=True, window=0, scale=None):
@@ -59,6 +60,34 @@ def test_decode_matches_last_row_of_prefill():
                                np.asarray(full[:, -1]),
                                rtol=2e-5, atol=2e-5)
 
+
+
+@pytest.mark.parametrize("S,window,pos", [
+    (16, 0, 0),     # empty prefix: the new row is all there is
+    (16, 0, 9),     # mid-cache: slots past pos hold stale values
+    (8, 8, 13),     # wrapped ring: slot 13 % 8 holds position 5
+])
+def test_decode_with_row_matches_written_cache(S, window, pos):
+    """Attention over the unmodified cache plus the new row equals
+    attention over the cache with the row written at its slot."""
+    rng = np.random.default_rng(2)
+    B, Hq, Hkv, dh = 2, 4, 2, 16
+
+    def normal(*shape):
+        return jnp.asarray(rng.normal(size=shape), jnp.float32)
+    q = normal(B, 1, Hq, dh)
+    k_cache, v_cache = normal(B, S, Hkv, dh), normal(B, S, Hkv, dh)
+    k_new, v_new = normal(B, 1, Hkv, dh), normal(B, 1, Hkv, dh)
+    slot = decode_slot(pos, S, window)
+    slots = jnp.arange(S)
+    out = decode_attention_with_row(q, k_cache, v_cache,
+                                    (slots < pos) & (slots != slot),
+                                    k_new, v_new)
+    written = decode_attention(q, k_cache.at[:, slot].set(k_new[:, 0]),
+                               v_cache.at[:, slot].set(v_new[:, 0]),
+                               (slots <= pos) | (window > 0 and pos >= S))
+    np.testing.assert_allclose(np.asarray(out), np.asarray(written),
+                               rtol=2e-6, atol=2e-6)
 
 def test_mla_shapes_and_grad():
     from repro.config.base import get_config
