@@ -75,15 +75,16 @@ def main(argv=None, *, root: Path = H.CHECKOUT,
                     and any(len(p) + m == longest
                             for b in batches[:i] for p, m in b))
         if engine is None:
-            engine = system.build_engine(cfg, used, seed)
+            engine = system.build_engine(cell.arch, cfg, used, seed)
             run_cell.warm_up(engine, batches)
         else:
-            system.set_weights(engine, cfg, seed)
+            system.set_weights(engine, cell.arch, cfg, seed)
         window = [run_cell.serve_window(engine, [b], 0.0)[0][0]
                   for b in batches[:need]]
         engine.params_home = None        # the reference needs the memory
         rows = H.check_sample(window, n_rows, seed)
-        g = reference.gaps(cfg, seed, rows, CONTROLS, device=used[0])
+        g = reference.gaps(cell.arch.logits, cfg, seed, rows, CONTROLS,
+                           device=used[0])
         rec = {"seed": seed, "rows": len(rows), "batches": need, **g,
                "correct": {k: H.judge(g[k], 0, cell.limits)[0]
                            for k in ("program", *CONTROLS)},

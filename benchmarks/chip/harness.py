@@ -1,9 +1,13 @@
 """Finds a cell's files by name and turns a run's record into metrics.
 
-Everything about one configuration, traffic mix, per-layer metric or cell
-limit is a file of its own, found from ``BENCHMARK.json`` by name:
+Everything about one configuration, architecture, traffic mix, per-layer
+metric or cell limit is a file of its own, found from ``BENCHMARK.json``
+by name:
 
 - ``configs/<config>.json``: the file a configuration entry names;
+- ``architectures/<name>.py``: everything that knows a layer's shape, for
+  each configuration whose ``architectures[0]`` is ``name`` (as in a
+  published ``config.json``);
 - ``traffic/<traffic>.json``: parameters for ``traffic.schedule``;
 - ``metrics/<metric>.py``: a reader with ``read(run) -> float | None``
   (``None``: nothing to read in this run, and the metric is left out)
@@ -11,7 +15,24 @@ limit is a file of its own, found from ``BENCHMARK.json`` by name:
 - ``limits/<cell>.json``: the limit on each number ``correct`` compares;
 - ``peaks.json``: the chip's peaks, keyed by ``device_kind``.
 
-So a new cell, mix or metric is new files and new entries, not new code.
+So a new cell, mix, metric or architecture is new files and new entries,
+not new code.
+
+An architecture file provides:
+
+- ``sizes(config)``: the widths, hashable (``weights.Sizes``);
+- ``program_config(config)``: the program's ``ModelConfig``, and
+  ``program_params(model, sizes, key)``: the seed's weights in the
+  program's tree and shardings, made on the devices in one jitted call
+  (``weights.on_devices``), each leaf drawn by ``weights.draw``;
+- ``logits(config, seed, seqs, positions, mode, device)``: the plain
+  float32 reference at ``HIGHEST`` precision, its weights drawn layer by
+  layer, every matrix product through ``reference.matmul(x, w, mode)`` so
+  that ``mode`` ``"int8"`` or ``"fp8"`` gives the controls;
+- the counts the readers use: ``params(s)``, ``prefill_flops(s,
+  prompt_lens)``, ``decode_flops(s, contexts)``, ``decode_step_bytes(s,
+  contexts, chips)``, ``flash_flops(s, batch, t)`` and ``flash_bytes(s,
+  batch, t)``.
 """
 
 from __future__ import annotations
@@ -44,6 +65,7 @@ class Cell:
     paths: str            # the benchmark's directory, from the root
     chips: int
     config: dict
+    arch: object          # the configuration's architecture file
     traffic: dict
     limits: dict
     end_to_end: list      # BENCHMARK.json entries that this cell reports
@@ -63,9 +85,13 @@ def load_cell(name: str, root: Path = CHECKOUT) -> Cell:
     w = cells[name]
     conf = {c["name"]: c for c in bench["configs"]}[w["config"]]
     here = root / bench["paths"][0]
+    config = read_json(root / conf["file"])
+    if not config.get("architectures"):
+        raise BenchError(f"{conf['file']} names no architecture (its "
+                         f"'architectures' key)")
     return Cell(
-        name=name, paths=bench["paths"][0], chips=w["chips"],
-        config=read_json(root / conf["file"]),
+        name=name, paths=bench["paths"][0], chips=w["chips"], config=config,
+        arch=architecture(config["architectures"][0], here),
         traffic=read_json(here / "traffic" / f"{w['traffic']}.json"),
         limits=read_json(here / "limits" / f"{name}.json"),
         end_to_end=[m for m in bench["end_to_end"] if _applies(m, name)],
@@ -80,15 +106,25 @@ def peaks(device_kind: str, root: Path = HERE) -> dict:
     return table["devices"][device_kind]
 
 
+def _module(path: Path, name: str):
+    spec = importlib.util.spec_from_file_location(name, path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
 def reader(metric: str, root: Path = HERE):
     path = root / "metrics" / f"{metric}.py"
     if not path.exists():
         raise BenchError(f"metric {metric!r} has no reader at {path}")
-    spec = importlib.util.spec_from_file_location(
-        f"metric_{metric.replace('.', '_')}", path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod
+    return _module(path, f"metric_{metric.replace('.', '_')}")
+
+
+def architecture(name: str, root: Path = HERE):
+    path = root / "architectures" / f"{name}.py"
+    if not path.exists():
+        raise BenchError(f"architecture {name!r} has no file at {path}")
+    return _module(path, f"arch_{name}")
 
 
 @dataclasses.dataclass
@@ -118,7 +154,7 @@ class Batch:
 class Run:
     """What a per-layer reader gets."""
     cell: Cell
-    sizes: dict            # weights.sizes of the configuration
+    sizes: dict            # the architecture's sizes of the configuration
     peak: dict             # peaks.json entry of this chip
     batches: list          # [Batch]
     trace: object = None   # trace_reduce.Trace, in a --trace 1 run

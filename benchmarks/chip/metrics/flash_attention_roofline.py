@@ -2,8 +2,8 @@
 
 The least time the kernel could take for the prefills run, the larger of
 its FLOPs over the bf16 peak and its bytes over HBM bandwidth
-(``work.flash_flops`` / ``work.flash_bytes``, per chip), over the
-kernel's own device time in the trace, averaged over the chips.
+(the architecture's ``flash_flops`` / ``flash_bytes``, per chip), over
+the kernel's own device time in the trace, averaged over the chips.
 ``describe`` names the bound that binds.
 """
 
@@ -12,7 +12,6 @@ import re
 import numpy as np
 
 import trace_reduce as TR
-import work
 
 # the custom call of repro.kernels.flash_attention, named for its function
 KERNEL = re.compile(r"^flash_attention(\.\d+)?$")
@@ -20,9 +19,9 @@ KERNEL = re.compile(r"^flash_attention(\.\d+)?$")
 
 def bounds(run):
     """(FLOP-bound seconds, byte-bound seconds) per chip."""
-    f = sum(work.flash_flops(run.sizes, b.size, b.padded_len)
+    f = sum(run.cell.arch.flash_flops(run.sizes, b.size, b.padded_len)
             for b in run.batches)
-    n = sum(work.flash_bytes(run.sizes, b.size, b.padded_len)
+    n = sum(run.cell.arch.flash_bytes(run.sizes, b.size, b.padded_len)
             for b in run.batches)
     return (f / run.chips / run.peak["bf16_flops"],
             n / run.chips / run.peak["hbm_bytes_per_s"])

@@ -16,7 +16,8 @@ flight has finished. The window ends there; rates divide by it.
 window under the profiler and reports the per-layer metrics read from its
 trace. Either way, once the window has closed and the engine is freed, a
 sample of the finished requests is compared with the float32 reference
-(``reference.py``) and decides ``correct``. The last line of standard
+(the configuration's architecture's ``logits``, scored by
+``reference.py``) and decides ``correct``. The last line of standard
 output is the result as one JSON object.
 """
 
@@ -46,7 +47,6 @@ import reference  # noqa: E402
 import system  # noqa: E402
 import trace_reduce as TR  # noqa: E402
 import traffic  # noqa: E402
-import weights  # noqa: E402
 from repro.launch.compile_cache import enable_compile_cache  # noqa: E402
 
 PREFILL, DECODE = "bench.prefill", "bench.decode"
@@ -170,7 +170,7 @@ def main(argv=None, *, root: Path = H.CHECKOUT, require_tpu: bool = True):
 
     cfg = cell.config
     batches = traffic.schedule(cell.traffic, cfg["vocab_size"], args.seed)
-    engine = system.build_engine(cfg, used, args.seed)
+    engine = system.build_engine(cell.arch, cfg, used, args.seed)
     warm_up(engine, batches)
     counter = CompileCounter()
     setup_s = time.perf_counter() - T0
@@ -202,7 +202,7 @@ def main(argv=None, *, root: Path = H.CHECKOUT, require_tpu: bool = True):
         result = {}
         if trace_dir:
             tr = TR.load(next(Path(trace_dir).rglob("*.xplane.pb")))
-            run = H.Run(cell, weights.sizes(cfg), peak, window, tr)
+            run = H.Run(cell, cell.arch.sizes(cfg), peak, window, tr)
             metrics = per_layer(run, cell)
             lo, hi = tr.extent()
             device["busy_s"] = TR.mean_busy_ns(tr, [(lo, hi)]) * 1e-9
@@ -221,7 +221,8 @@ def main(argv=None, *, root: Path = H.CHECKOUT, require_tpu: bool = True):
     failed = sum(H.malformed(b, cfg["vocab_size"]) for b in window)
     rows = H.check_sample(window, cell.traffic["check_rows"], args.seed)
     t = time.perf_counter()
-    g = reference.gaps(cfg, args.seed, rows, device=used[0])
+    g = reference.gaps(cell.arch.logits, cfg, args.seed, rows,
+                       device=used[0])
     correct, checks = H.judge(g["program"], failed, cell.limits)
     say(f"reference: {len(rows)} requests, {g['tokens']} served tokens, "
         f"{time.perf_counter() - t:.3f} s")

@@ -3,6 +3,7 @@
 from __future__ import annotations
 
 import json
+import shutil
 import sys
 from pathlib import Path
 
@@ -11,8 +12,11 @@ import pytest
 HERE = Path(__file__).resolve().parents[1]
 sys.path.insert(0, str(HERE))
 
+import harness as H  # noqa: E402
+
 TINY_CONFIG = {
-    "name": "tiny", "source": "test", "program_arch": "yi-9b",
+    "name": "tiny", "source": "test", "architectures": ["LlamaForCausalLM"],
+    "program_arch": "yi-9b",
     "hidden_size": 256, "intermediate_size": 512, "num_attention_heads": 4,
     "num_key_value_heads": 2, "head_dim": 128, "num_hidden_layers": 2,
     "vocab_size": 512, "rope_theta": 10000.0, "rms_norm_eps": 1e-06,
@@ -25,12 +29,17 @@ TINY_TRAFFIC = {
     "batches": 4, "check_rows": 3}
 # set from the tiny cell's own readings (test_control.py prints them)
 TINY_LIMIT = 0.02
+LLAMA = H.architecture("LlamaForCausalLM")
 
 
 def write_cell(root: Path, config=TINY_CONFIG, traffic=TINY_TRAFFIC,
                limit=TINY_LIMIT, chips=1) -> str:
-    """A cell ``tiny`` added as files plus entries in BENCHMARK.json."""
+    """A cell ``tiny`` added as files plus entries in BENCHMARK.json, in
+    a root that holds the benchmark's architecture files."""
     d = root / "bench"
+    shutil.copytree(HERE / "architectures", d / "architectures",
+                    ignore=shutil.ignore_patterns("__pycache__"),
+                    dirs_exist_ok=True)
     for sub in ("configs", "traffic", "limits", "metrics"):
         (d / sub).mkdir(parents=True, exist_ok=True)
     (d / "configs" / "tiny.json").write_text(json.dumps(config))

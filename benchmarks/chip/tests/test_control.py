@@ -10,7 +10,7 @@ import numpy as np
 import pytest
 
 import reference
-from conftest import TINY_CONFIG, TINY_LIMIT
+from conftest import LLAMA, TINY_CONFIG, TINY_LIMIT
 
 
 def rows(seed, n=4, prompt=128, served=64):
@@ -23,7 +23,7 @@ def rows(seed, n=4, prompt=128, served=64):
 @pytest.mark.parametrize("seed", [1, 2 ** 33 + 1, 77])
 @pytest.mark.parametrize("mode", ["int8", "fp8"])
 def test_control_fails_the_limit(mode, seed):
-    g = reference.gaps(TINY_CONFIG, seed, rows(seed), [mode])
+    g = reference.gaps(LLAMA.logits, TINY_CONFIG, seed, rows(seed), [mode])
     assert g[mode]["max"] > TINY_LIMIT, g
 
 
@@ -33,9 +33,10 @@ def test_reference_reads_no_gap_on_its_own_tokens():
     (prompt, _), = rows(seed, n=1)
     seq = prompt
     for _ in range(8):          # greedy continuation by the reference
-        (lg,) = reference.logits(TINY_CONFIG, seed, [seq], [[len(seq) - 1]])
+        (lg,) = LLAMA.logits(TINY_CONFIG, seed, [seq], [[len(seq) - 1]])
         seq = np.append(seq, np.int32(np.argmax(np.asarray(lg)[0])))
-    g = reference.gaps(TINY_CONFIG, seed, [(prompt, seq[len(prompt):])])
+    g = reference.gaps(LLAMA.logits, TINY_CONFIG, seed,
+                       [(prompt, seq[len(prompt):])])
     assert g["program"]["max"] == 0.0 and g["tokens"] == 8
 
 

@@ -9,12 +9,14 @@ import pytest
 import harness as H
 import traffic
 import weights
-from conftest import TINY_CONFIG, TINY_TRAFFIC
+from conftest import LLAMA, TINY_CONFIG, TINY_TRAFFIC
 
 
 def test_tiny_cell_added_as_files_is_found(tiny_root):
     cell = H.load_cell("tiny-cell", tiny_root)
     assert cell.config == TINY_CONFIG
+    assert cell.arch.__file__ == str(
+        tiny_root / "bench" / "architectures" / "LlamaForCausalLM.py")
     assert cell.traffic == TINY_TRAFFIC
     assert cell.limits["max_gap"]["limit"] > 0
     assert [m["name"] for m in cell.end_to_end] == [
@@ -115,11 +117,11 @@ def test_large_seeds_give_distinct_keys():
 
 def test_stacked_weights_equal_per_layer_draws():
     import jax.numpy as jnp
-    s = weights.sizes(dict(TINY_CONFIG, vocab_size=64))
+    s = LLAMA.sizes(dict(TINY_CONFIG, vocab_size=64))
     key = weights.seed_key(2 ** 40 + 9)
-    st = weights.stacked(key, s)
+    st = LLAMA.stacked(key, s)
     for layer in range(s["layers"]):
-        one = weights.layer_weights(key, s, layer)
+        one = LLAMA.layer_weights(key, s, layer)
         for n, a in one.items():
             assert a.dtype == jnp.bfloat16
             assert bool(jnp.array_equal(st[n][layer], a)), (n, layer)
